@@ -1,0 +1,1 @@
+"""See ``benchmarks/serving/__init__.py``."""
