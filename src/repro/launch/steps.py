@@ -81,11 +81,16 @@ def make_greedy_decode_step(cfg: ModelConfig):
 
     ``kv_bucket`` must be a static argument of the surrounding jit — each
     bucket traces its own length-aware attention (see
-    models.layers.set_decode_kv_bucket)."""
+    models.layers.set_decode_kv_bucket).  Its device ops are named under
+    ``decode/model`` and ``decode/sample``; jitted, the program is
+    ``jit_step``."""
     def step(params, tokens, cache, kv_bucket=None):
-        logits, cache = decode_step(cfg, params, tokens, cache,
-                                    kv_bucket=kv_bucket)
-        return jnp.argmax(logits, -1).astype(jnp.int32), logits, cache
+        with jax.named_scope("decode/model"):
+            logits, cache = decode_step(cfg, params, tokens, cache,
+                                        kv_bucket=kv_bucket)
+        with jax.named_scope("decode/sample"):
+            toks = jnp.argmax(logits, -1).astype(jnp.int32)
+        return toks, logits, cache
     return step
 
 

@@ -27,6 +27,12 @@ Like the engine, the loop never reads a device value: the schedule depends
 only on statically known prompt/gen lengths, and all tokens are fetched in
 one sync at the end.
 
+Each ``run`` call records its host phases as spans (``serve.wave``,
+``serve.bank``, ``serve.admit``, ``serve.decode``, ``serve.fetch``,
+``serve.unpack``; ``telemetry.SpanRecorder``) and each request's admission
+and completion on the step clock, the count of batched decode steps
+dispatched so far; ``stats`` carries both.
+
 The same slot bookkeeping also drives ``PipelineServeEngine`` (continuous
 batching *across* pipeline stages): the engine supplies per-stage cache
 banks (``slot_bank``), per-request admission (``admit_slot``), the chained
@@ -49,6 +55,7 @@ from repro.models import init_serve_cache, prefill
 
 from .engine import _quiet
 from .pipeline import StageDown
+from .telemetry import SpanRecorder
 
 
 @dataclasses.dataclass
@@ -88,24 +95,31 @@ def _insert_leaf(full, one, slot, b_ax):
 
 
 class SlotScheduler:
-    """Continuous batching: admit/evict requests into `slots` cache rows."""
+    """Continuous batching: admit/evict requests into `slots` cache rows.
 
-    def __init__(self, engine, slots: int):
+    clock: the span recorder's clock (default ``time.perf_counter``);
+    it times the host phases reported in ``stats`` and nothing else."""
+
+    def __init__(self, engine, slots: int, clock=time.perf_counter):
         self.engine = engine
         self.slots = int(slots)
+        self._clock = clock
         self._batch_axes = None
         cfg = engine.cfg
 
         def _admit(params, tokens, extras, cache_slots, slot_tokens, slot):
             batch = {"tokens": tokens, **extras}
-            c1 = init_serve_cache(cfg, 1, tokens.shape[1], batch=batch)
-            logits, c1 = prefill(cfg, params, batch, c1)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            cache_slots = jax.tree.map(
-                lambda full, one, ax: _insert_leaf(full, one, slot, ax),
-                cache_slots, c1, self._batch_axes)
-            slot_tokens = jax.lax.dynamic_update_slice(slot_tokens, tok,
-                                                       (slot, 0))
+            with jax.named_scope("admit/prefill"):
+                c1 = init_serve_cache(cfg, 1, tokens.shape[1], batch=batch)
+                logits, c1 = prefill(cfg, params, batch, c1)
+            with jax.named_scope("admit/first_token"):
+                tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            with jax.named_scope("admit/scatter"):
+                cache_slots = jax.tree.map(
+                    lambda full, one, ax: _insert_leaf(full, one, slot, ax),
+                    cache_slots, c1, self._batch_axes)
+                slot_tokens = jax.lax.dynamic_update_slice(slot_tokens, tok,
+                                                           (slot, 0))
             return tok, cache_slots, slot_tokens
 
         # slot_tokens is NOT donated: per-step token arrays are retained on
@@ -150,49 +164,71 @@ class SlotScheduler:
         in-flight request into its slot for the stages whose primary
         moved (``migrate_and_replay``; replica adds are capacity-only).
         Streams stay identical to an undisturbed run — the ``-replan``
-        cells of the serve equivalence fixture pin this."""
+        cells of the serve equivalence fixture pin this.
+
+        stats: ``wall_s`` (the ``serve.wave`` span's seconds),
+        ``decode_steps``, ``slot_utilization`` (busy slot-steps over all),
+        ``spans`` (``{name: [count, seconds]}``) and, on the fast path,
+        ``requests``: ``{rid: [admit_step, done_step]}``, the decode steps
+        dispatched when the request was admitted (its first token) and
+        after its last token was produced, so ``done_step - admit_step ==
+        gen_len - 1``."""
         if not requests:
             return [], {"wall_s": 0.0, "decode_steps": 0,
-                        "slot_utilization": 0.0}
+                        "slot_utilization": 0.0, "spans": {},
+                        "requests": {}}
         for r in requests:
             self.engine._check_fit(r.tokens.shape[1], r.gen_len)
 
-        if engine == "reference":
-            # per-request isolation: the oracle the slot path must match
-            streams = []
-            # wall_s is a reported stat, never schedule-affecting
-            t0 = time.perf_counter()  # repro: ignore[determinism]
-            for r in requests:
-                batch = {"tokens": jnp.asarray(r.tokens),
-                         **{k: jnp.asarray(v)
-                            for k, v in (r.extras or {}).items()}}
-                toks = self.engine.generate(batch, r.gen_len,
-                                            engine="reference")
-                streams.append(toks[0])
-            wall = time.perf_counter() - t0  # repro: ignore[determinism]
-            stats = {"wall_s": wall, "decode_steps": 0,
-                     "slot_utilization": 1.0}
-            return streams, stats
+        rec = SpanRecorder(self._clock)
+        events = None
+        with rec.span("serve.wave"):
+            if engine == "reference":
+                streams, n_steps, util = self._reference(requests), 0, 1.0
+            else:
+                streams, n_steps, busy, events = self._serve(
+                    requests, kill, replan, rec)
+                util = busy / max(1, n_steps * self.slots)
+        stats = {"wall_s": rec.seconds("serve.wave"),
+                 "decode_steps": n_steps, "slot_utilization": util,
+                 "spans": rec.snapshot()}
+        if events is not None:
+            stats["requests"] = events
+        return streams, stats
 
+    def _reference(self, requests):
+        """Per-request isolation: the oracle the slot path must match."""
+        streams = []
+        for r in requests:
+            batch = {"tokens": jnp.asarray(r.tokens),
+                     **{k: jnp.asarray(v)
+                        for k, v in (r.extras or {}).items()}}
+            toks = self.engine.generate(batch, r.gen_len, engine="reference")
+            streams.append(toks[0])
+        return streams
+
+    def _serve(self, requests, kill, replan, rec):
+        """The slot path; returns (streams, decode steps, busy slot-steps,
+        per-request step events)."""
         eng = self.engine
         cfg, B = eng.cfg, self.slots
         pipeline = getattr(eng, "is_pipeline", False)
         proto_extras = requests[0].extras or {}
-        proto_batch = {"tokens": jnp.zeros((B, 1), jnp.int32)}
-        for k, v in proto_extras.items():
-            proto_batch[k] = jnp.zeros((B,) + v.shape[1:], v.dtype)
-        if pipeline:
-            # per-stage cache banks; admission/scatter live on the engine
-            cache = eng.slot_bank(B, proto_batch)
-        else:
-            if self._batch_axes is None:
-                self._batch_axes = self._leaf_batch_axes(proto_extras)
-            cache = init_serve_cache(cfg, B, eng.max_len, batch=proto_batch)
-        slot_tokens = jnp.zeros((B, 1), jnp.int32)
+        with rec.span("serve.bank"):
+            proto_batch = {"tokens": jnp.zeros((B, 1), jnp.int32)}
+            for k, v in proto_extras.items():
+                proto_batch[k] = jnp.zeros((B,) + v.shape[1:], v.dtype)
+            if pipeline:
+                # per-stage cache banks; admission/scatter live on the engine
+                cache = eng.slot_bank(B, proto_batch)
+            else:
+                if self._batch_axes is None:
+                    self._batch_axes = self._leaf_batch_axes(proto_extras)
+                cache = init_serve_cache(cfg, B, eng.max_len,
+                                         batch=proto_batch)
+            slot_tokens = jnp.zeros((B, 1), jnp.int32)
         tel = getattr(eng, "telemetry", None)
 
-        # wall_s is a reported stat, never schedule-affecting
-        t0 = time.perf_counter()  # repro: ignore[determinism]
         next_idx = 0
         active: dict[int, list] = {}          # slot -> [request, n_emitted]
         free = list(range(B))
@@ -200,6 +236,7 @@ class SlotScheduler:
         first_tok: dict[int, object] = {}     # rid -> (1,1) device token
         step_toks: list = []                  # per-step (B,1) device tokens
         step_maps: list[dict[int, int]] = []  # per-step slot -> rid
+        events: dict[int, list[int]] = {}     # rid -> [admit, done] step
         n_steps = busy = 0
 
         kills = ([] if kill is None
@@ -220,17 +257,20 @@ class SlotScheduler:
                 next_idx += 1
                 admitted += 1
                 slot = free.pop(0)
-                extras = {k: jnp.asarray(v)
-                          for k, v in (r.extras or {}).items()}
-                if pipeline:
-                    tok, cache, slot_tokens = eng.admit_slot(
-                        jnp.asarray(r.tokens), extras, cache, slot_tokens,
-                        slot)
-                else:
-                    tok, cache, slot_tokens = _quiet(
-                        self._admit, eng.params, jnp.asarray(r.tokens),
-                        extras, cache, slot_tokens, np.int32(slot))
+                with rec.span("serve.admit", rid=r.rid, slot=slot,
+                              prompt_len=r.tokens.shape[1]):
+                    extras = {k: jnp.asarray(v)
+                              for k, v in (r.extras or {}).items()}
+                    if pipeline:
+                        tok, cache, slot_tokens = eng.admit_slot(
+                            jnp.asarray(r.tokens), extras, cache,
+                            slot_tokens, slot)
+                    else:
+                        tok, cache, slot_tokens = _quiet(
+                            self._admit, eng.params, jnp.asarray(r.tokens),
+                            extras, cache, slot_tokens, np.int32(slot))
                 first_tok[r.rid] = tok
+                events[r.rid] = [n_steps, n_steps]
                 slot_len[slot] = r.tokens.shape[1]
                 if r.gen_len > 1:
                     active[slot] = [r, 1]
@@ -284,19 +324,22 @@ class SlotScheduler:
                 tel.record_queue_depth(len(active))
             bucket = eng.bucket_for(
                 int(max(slot_len[s] for s in active)) + 1)
-            while True:
-                try:
-                    slot_tokens, _, cache = eng._decode_quiet(
-                        slot_tokens, cache, bucket)
-                    break
-                except StageDown:
-                    # a silent failure just got confirmed DEAD mid-chain:
-                    # restore the stage and replay every in-flight request
-                    # into its slot, then retry the batched step
-                    inflight = [(s, st[0], st[1])
-                                for s, st in sorted(active.items())]
-                    cache, slot_tokens = eng.recover_and_replay(
-                        inflight, cache, slot_tokens, proto_batch)
+            with rec.span("serve.decode", step=n_steps, bucket=bucket,
+                          active=len(active)):
+                while True:
+                    try:
+                        slot_tokens, _, cache = eng._decode_quiet(
+                            slot_tokens, cache, bucket)
+                        break
+                    except StageDown:
+                        # a silent failure just got confirmed DEAD
+                        # mid-chain: restore the stage and replay every
+                        # in-flight request into its slot, then retry the
+                        # batched step
+                        inflight = [(s, st[0], st[1])
+                                    for s, st in sorted(active.items())]
+                        cache, slot_tokens = eng.recover_and_replay(
+                            inflight, cache, slot_tokens, proto_batch)
             slot_len += 1                      # every row writes, active or not
             n_steps += 1
             busy += len(active)
@@ -305,20 +348,20 @@ class SlotScheduler:
             for slot in list(active):
                 active[slot][1] += 1
                 if active[slot][1] >= active[slot][0].gen_len:
+                    events[active[slot][0].rid][1] = n_steps
                     del active[slot]
                     free.append(slot)
             free.sort()
 
         # single host sync: fetch every step's tokens at once
-        stacked = (np.asarray(jnp.concatenate(step_toks, axis=1))
-                   if step_toks else np.zeros((B, 0), np.int32))
-        streams = {r.rid: [int(np.asarray(first_tok[r.rid])[0, 0])]
-                   for r in requests}
-        for i, m in enumerate(step_maps):
-            for slot, rid in m.items():
-                streams[rid].append(int(stacked[slot, i]))
-        wall = time.perf_counter() - t0  # repro: ignore[determinism]
-        stats = {"wall_s": wall,
-                 "decode_steps": n_steps,
-                 "slot_utilization": busy / max(1, n_steps * B)}
-        return [np.asarray(streams[r.rid], np.int32) for r in requests], stats
+        with rec.span("serve.fetch"):
+            stacked = (np.asarray(jnp.concatenate(step_toks, axis=1))
+                       if step_toks else np.zeros((B, 0), np.int32))
+        with rec.span("serve.unpack"):
+            streams = {r.rid: [int(np.asarray(first_tok[r.rid])[0, 0])]
+                       for r in requests}
+            for i, m in enumerate(step_maps):
+                for slot, rid in m.items():
+                    streams[rid].append(int(stacked[slot, i]))
+            out = [np.asarray(streams[r.rid], np.int32) for r in requests]
+        return out, n_steps, busy, events

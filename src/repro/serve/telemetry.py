@@ -18,6 +18,11 @@ Two pieces close the elastic-serving control loop
   (``fold``) or direct observations.  ``as_cluster()`` materializes a
   ``ClusterGraph`` for ``incremental_replan``.
 
+A third piece serves measurement rather than the control loop:
+:class:`SpanRecorder` times the host phases of one ``SlotScheduler.run``
+call (per-name count and seconds, on the same kind of injected clock) and
+writes each phase into the profiler's trace as a named span.
+
 Samples are plain floats on the host; recording never touches device
 values beyond what the engine already synchronized, so enabling telemetry
 cannot change a token stream (the serving token-identity contract).
@@ -25,8 +30,10 @@ cannot change a token stream (the serving token-identity contract).
 
 from __future__ import annotations
 
+import contextlib
 import time
 
+import jax
 import numpy as np
 
 
@@ -122,6 +129,41 @@ class TelemetryStream:
             "queue_depth": self.queue_depth.values().tolist(),
             "samples_total": int(sum(r.total for r in self.decode_s)),
         }
+
+
+class SpanRecorder:
+    """Per-name totals of host spans, on an injected clock.
+
+    ``span(name, **args)`` times the enclosed block with ``self._clock``
+    (default ``time.perf_counter``, passed as a reference, as
+    :class:`TelemetryStream` takes it) and adds one to the name's count
+    and the block's seconds to its total.  The block also runs inside a
+    ``jax.profiler.TraceAnnotation(name, **args)``, so a profiler trace
+    holds the same spans on the device's clock, with ``args`` as their
+    stats.  Recording is O(1) per span and reads no device value.
+    """
+
+    def __init__(self, clock=time.perf_counter):
+        self._clock = clock
+        self.totals: dict[str, list] = {}      # name -> [count, seconds]
+
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        with jax.profiler.TraceAnnotation(name, **args):
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                tot = self.totals.setdefault(name, [0, 0.0])
+                tot[0] += 1
+                tot[1] += self._clock() - t0
+
+    def seconds(self, name: str) -> float:
+        return self.totals.get(name, (0, 0.0))[1]
+
+    def snapshot(self) -> dict:
+        """{name: [count, seconds]}, a copy."""
+        return {k: list(v) for k, v in self.totals.items()}
 
 
 class ClusterState:
