@@ -65,13 +65,16 @@ def init_dense_block(key, cfg: ModelConfig, d_ff=None, causal_cross=False):
 
 
 def apply_dense_block(p, h, cfg: ModelConfig, positions, cache=None,
-                      causal=True):
+                      causal=True, layer=None):
+    """layer: ``cache`` is the stacked cache of every layer and this block
+    updates plane ``layer`` of it in place (``layers.attention``)."""
     x = rms_norm(h, p["ln1"], cfg.norm_eps)
     if cfg.use_mla:
-        a, nc = mla_attention(p["attn"], x, cfg, positions, cache=cache)
+        a, nc = mla_attention(p["attn"], x, cfg, positions, cache=cache,
+                              layer=layer)
     else:
         a, nc = attention(p["attn"], x, cfg, positions, causal=causal,
-                          cache=cache)
+                          cache=cache, layer=layer)
     h = h + a
     h = h + mlp(p["mlp"], rms_norm(h, p["ln2"], cfg.norm_eps))
     return h, nc
@@ -217,21 +220,35 @@ def _dense_init(cfg, key):
     return p
 
 
+def _n_blocks(blocks):
+    return jax.tree_util.tree_leaves(blocks)[0].shape[0]
+
+
 def _dense_apply(cfg, params, h, positions, cache=None, kind="train"):
-    def body(carry, xs):
-        if cache is None:
-            bp = xs
+    if cache is None:
+        def body(carry, bp):
             h, _ = apply_dense_block(bp, carry, cfg, positions)
             return h, None
-        bp, c = xs
-        h, nc = apply_dense_block(bp, carry, cfg, positions, cache=c)
-        return h, nc
 
-    f = jax.checkpoint(body) if (cfg.remat and kind == "train") else body
-    xs = params["blocks"] if cache is None else (params["blocks"], cache)
-    unroll = cfg.n_layers if (cfg.serve_unroll and kind == "decode") else 1
-    h, new_cache = jax.lax.scan(f, h, xs, unroll=unroll)
-    return h, new_cache, 0.0
+        f = jax.checkpoint(body) if (cfg.remat and kind == "train") else body
+        h, _ = jax.lax.scan(f, h, params["blocks"])
+        return h, None, 0.0
+
+    # the stacked cache rides in the carry: each layer writes its new rows
+    # into its own plane in place, so no step builds a second cache; len
+    # advances once, after the loop
+    def body(carry, xs):
+        h, c = carry
+        bp, i = xs
+        return apply_dense_block(bp, h, cfg, positions, cache=c, layer=i), \
+            None
+
+    n_blk = _n_blocks(params["blocks"])
+    unroll = n_blk if (cfg.serve_unroll and kind == "decode") else 1
+    (h, cache), _ = jax.lax.scan(body, (h, cache),
+                                 (params["blocks"], jnp.arange(n_blk)),
+                                 unroll=unroll)
+    return h, {**cache, "len": cache["len"] + h.shape[1]}, 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -324,12 +341,8 @@ def _ssm_apply(cfg, params, h, positions, cache=None, kind="train",
     every = cfg.hybrid_attn_every if shared is not None else 0
 
     def body(carry, xs):
-        h, shared_kv = carry
-        if cache is None:
-            bp, idx = xs
-            mcache = None
-        else:
-            bp, mcache, idx = xs
+        h, shared_kv, mc = carry
+        bp, idx = xs
 
         if every:
             def with_attn(h, skv):
@@ -354,25 +367,28 @@ def _ssm_apply(cfg, params, h, positions, cache=None, kind="train",
                 h, shared_kv = jax.lax.cond(
                     is_app, with_attn, no_attn, h, shared_kv)
 
-        y, nmc = mamba_block(bp, rms_norm(h, bp["pre_norm"], cfg.norm_eps),
-                             cfg, cache=mcache)
-        h = h + y
-        return (h, shared_kv), nmc
+        # with a cache, this layer's conv buffer and state go back into the
+        # stacked cache in place; len advances once, after the loop
+        y, mc = mamba_block(bp, rms_norm(h, bp["pre_norm"], cfg.norm_eps),
+                            cfg, cache=mc,
+                            layer=None if mc is None else idx - layer_offset)
+        return (h + y, shared_kv, mc), None
 
-    n_blk = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
+    n_blk = _n_blocks(params["blocks"])
     idxs = jnp.arange(layer_offset, layer_offset + n_blk)
-    shared_kv0 = None
-    mamba_caches = None
+    shared_kv0 = mc0 = None
     if cache is not None:
-        mamba_caches = cache["mamba"]
         shared_kv0 = cache.get("shared")
-    xs = (params["blocks"], idxs) if cache is None \
-        else (params["blocks"], mamba_caches, idxs)
+        mc0 = cache["mamba"]
+        # the conv buffer keeps the dtype the block computes it in
+        buf_dt = jnp.result_type(mc0["conv_buf"], params["blocks"]["in_proj"])
+        mc0 = {**mc0, "conv_buf": mc0["conv_buf"].astype(buf_dt)}
     f = jax.checkpoint(body) if (cfg.remat and kind == "train") else body
-    (h, shared_kv), new_mamba = jax.lax.scan(f, (h, shared_kv0), xs)
+    (h, shared_kv, mc), _ = jax.lax.scan(f, (h, shared_kv0, mc0),
+                                         (params["blocks"], idxs))
     new_cache = None
     if cache is not None:
-        new_cache = {"mamba": new_mamba}
+        new_cache = {"mamba": {**mc, "len": mc["len"] + h.shape[1]}}
         if shared_kv is not None:
             new_cache["shared"] = shared_kv
     return h, new_cache, 0.0

@@ -134,9 +134,13 @@ def ssd_reference(xh, dt, A, Bm, Cm, init_state=None):
     return jnp.stack(ys, axis=1).astype(xh.dtype), st
 
 
-def mamba_block(params, x, cfg: ModelConfig, cache=None):
+def mamba_block(params, x, cfg: ModelConfig, cache=None, layer=None):
     """Full Mamba2 block.  cache: None (train/prefill from scratch) or dict
     (conv_buf (B, K-1, C), state (B,h,p,n), len) for decode.
+    layer: ``cache`` is the stacked cache of every layer (a leading layer
+    axis); the block reads plane ``layer`` of it and writes its new conv
+    buffer and state back into that plane in place, leaving len for the
+    caller to advance once for all layers.
     Returns (y, new_cache)."""
     b, s, d = x.shape
     di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
@@ -150,8 +154,10 @@ def mamba_block(params, x, cfg: ModelConfig, cache=None):
     if cache is None:
         conv = jax.nn.silu(_causal_conv(conv_in, params["conv_w"], params["conv_b"]))
     else:
+        c = cache if layer is None else jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), cache)
         kw = cfg.ssm_conv
-        buf = jnp.concatenate([cache["conv_buf"], conv_in], axis=1)  # (B,K-1+s,C)
+        buf = jnp.concatenate([c["conv_buf"], conv_in], axis=1)  # (B,K-1+s,C)
         conv = sum(buf[:, i:i + s, :] * params["conv_w"][i][None, None, :]
                    for i in range(kw)) + params["conv_b"][None, None, :]
         conv = jax.nn.silu(conv)
@@ -165,25 +171,34 @@ def mamba_block(params, x, cfg: ModelConfig, cache=None):
     if cache is None:
         y, _ = ssd_chunked(xh, dt, A, B2, C2, min(cfg.ssm_chunk, s))
     elif s == 1:
-        st = cache["state"]
+        st = c["state"]
         dA = jnp.exp(dt[:, 0] * A)                        # (b,h)
         dBx = jnp.einsum("bh,bn,bhp->bhpn", dt[:, 0],
                          B2[:, 0].astype(jnp.float32),
                          xh[:, 0].astype(jnp.float32))
         st = st * dA[:, :, None, None] + dBx
+        new_cache = _store(cache, new_conv_buf, st, 1, layer)
         y = jnp.einsum("bn,bhpn->bhp", C2[:, 0].astype(jnp.float32), st)
         y = y[:, None].astype(x.dtype)                    # (b,1,h,p)
-        new_cache = {"conv_buf": new_conv_buf, "state": st,
-                     "len": cache["len"] + 1}
     else:                                                  # prefill into cache
         y, st = ssd_chunked(xh, dt, A, B2, C2, min(cfg.ssm_chunk, s))
-        new_cache = {"conv_buf": new_conv_buf, "state": st,
-                     "len": cache["len"] + s}
+        new_cache = _store(cache, new_conv_buf, st, s, layer)
 
     y = y + params["D"][None, None, :, None].astype(y.dtype) * xh.astype(y.dtype)
     y = y.reshape(b, s, di).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), params["norm_w"], cfg.norm_eps)
     return y @ params["out_proj"], new_cache
+
+
+def _store(cache, conv_buf, state, s, layer):
+    """The cache after a step of ``s`` tokens: with ``layer``, the stacked
+    cache with the new conv buffer and state written into plane ``layer``
+    in place, and len left as it was."""
+    if layer is None:
+        return {"conv_buf": conv_buf, "state": state, "len": cache["len"] + s}
+    return {**cache, **{k: jax.lax.dynamic_update_index_in_dim(
+        cache[k], v.astype(cache[k].dtype), layer, 0)
+        for k, v in (("conv_buf", conv_buf), ("state", state))}}
 
 
 def init_mamba_cache(cfg: ModelConfig, batch, dtype=jnp.bfloat16):

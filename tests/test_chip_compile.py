@@ -5,8 +5,10 @@ attached, ``v5e:2x2`` topology.  These tests compile every Pallas kernel and
 the granite-3-2b serving steps for one chip of it, so that what the chip's
 compiler refuses (a block that breaks the (8, 128) tiling rule, a primitive
 Mosaic cannot lower, a program that does not fit the chip's 16 GB) fails
-here, before any chip time is spent.  Nothing runs: a pass says nothing of
-results or times.
+here, before any chip time is spent.  The served decode step is also
+checked for a copy of the whole stacked cache (at the serving cells'
+shapes, and on the CPU at a tiny size).  Nothing runs: a pass says nothing
+of results or times.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -14,6 +16,7 @@ every test file.  Keep all such compiles in this one file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,3 +124,58 @@ def test_granite_full_decode_compiles_and_fits(one_chip):
         jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip),
         _on(one_chip, cache_specs(cfg, b, max_len)), max_len).compile()
     assert _fits(compiled) > 5e9
+
+
+def _leaf_copies(hlo, cache):
+    """The copies in compiled HLO text whose result has the shape of a
+    stacked cache leaf that holds rows or states (the int32 len counters,
+    one per layer and slot, are left out)."""
+    shapes = {a.shape for a in jax.tree.leaves(cache) if a.dtype != jnp.int32}
+    return [name for name, dims in
+            re.findall(r"%(\S+) = \w+\[([\d,]*)\]\S* copy\(", hlo)
+            if tuple(int(d) for d in dims.split(",") if d) in shapes]
+
+
+def _served_decode(cfg, slots, rows, bucket, sharding=None):
+    """The engine's decode step compiled for ``slots`` x ``rows`` at kv
+    bucket ``bucket`` -> (compiled, cache shapes)."""
+    def on(tree):
+        return tree if sharding is None else _on(sharding, tree)
+
+    step = jax.jit(make_greedy_decode_step(cfg), static_argnums=(3,),
+                   donate_argnums=(2,))
+    cache = cache_specs(cfg, slots, rows)
+    compiled = step.lower(
+        on(params_specs(cfg)),
+        on(jax.ShapeDtypeStruct((slots, 1), jnp.int32)), on(cache),
+        bucket).compile()
+    return compiled, cache
+
+
+@pytest.mark.parametrize("arch,slots,bucket", [
+    ("granite-3-2b", 64, 128), ("granite-3-2b", 64, 384),
+    ("mamba2-1.3b", 24, 384)], ids=["granite-b128", "granite-b384", "mamba2"])
+def test_served_decode_updates_cache_in_place(one_chip, arch, slots, bucket):
+    # the serving cells' shapes: 384 rows a slot.  The step writes the new
+    # rows and states into the donated cache where it lies: no copy of a
+    # whole stacked leaf, and no temp buffer of the cache's size
+    cfg = get_config(arch, "full")
+    compiled, cache = _served_decode(cfg, slots, 384, bucket, one_chip)
+    assert not _leaf_copies(compiled.as_text(), cache)
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "mamba2-1.3b", "zamba2-7b"])
+def test_served_decode_in_place_on_cpu(arch):
+    """The CPU twin of the test above, at the smoke size: no copy of a whole
+    stacked cache leaf in the step's entry computation, where a layer loop
+    that built a second cache copies it into the donated output.  Inside
+    the loop XLA:CPU still copies some leaves that the TPU compiler
+    updates in place, so only the TPU test holds the whole program to no
+    copies."""
+    cfg = get_config(arch, "smoke")
+    compiled, cache = _served_decode(cfg, 4, 48, 32)
+    hlo = compiled.as_text()
+    assert not _leaf_copies(hlo[hlo.index("\nENTRY"):], cache)

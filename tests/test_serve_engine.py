@@ -139,6 +139,62 @@ def test_scheduler_slot_reuse_and_order():
     assert 0.0 < stats["slot_utilization"] <= 1.0
 
 
+class _StepLogits:
+    """The engine as the scheduler sees it, keeping each decode step's
+    logits; everything else is the engine's."""
+
+    def __init__(self, engine):
+        self._engine = engine
+        self.logits = []
+
+    def __getattr__(self, name):
+        return getattr(self._engine, name)
+
+    def _decode_quiet(self, toks, cache, bucket):
+        toks, logits, cache = self._engine._decode_quiet(toks, cache, bucket)
+        self.logits.append(logits)
+        return toks, logits, cache
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "mamba2-1.3b", "zamba2-7b"])
+def test_staggered_slots_decode_in_place(arch):
+    """Slots at different lengths share each decode step, which updates the
+    stacked cache in place and attends over a kv bucket below max_len:
+    every request's decode logits match a full forward over its own prompt
+    and tokens (a path that keeps no cache)."""
+    eng = _engine(arch, max_len=48, kv_block=16)
+    log = _StepLogits(eng)
+    sched = SlotScheduler(log, slots=3)
+    slots = []                                # admission order = rid order
+    admit = sched._admit
+
+    def _admit(*args):
+        slots.append(int(args[-1]))
+        return admit(*args)
+
+    sched._admit = _admit
+    lens = [(5, 9), (12, 4), (7, 11), (20, 6), (9, 8), (14, 5)]
+    reqs = [Request(rid=i,
+                    tokens=np.asarray(make_batch(eng.cfg, 1, p, 120 + i)
+                                      ["tokens"]),
+                    gen_len=g) for i, (p, g) in enumerate(lens)]
+    streams, stats = sched.run(reqs)
+    assert max(p + g for p, g in lens) - 1 <= 32 < eng.max_len
+    assert len(set(stats["requests"][r.rid][0] for r in reqs)) > 2
+    for r, slot, toks in zip(reqs, slots, streams):
+        first = stats["requests"][r.rid][0]
+        p = r.tokens.shape[1]
+        seq = np.concatenate([r.tokens[0], toks[:-1]])[None]
+        full, _ = forward(eng.cfg, eng.params, {"tokens": jnp.asarray(seq)},
+                          kind="eval")
+        full = np.asarray(full)[0]
+        for k in range(1, r.gen_len):
+            got = np.asarray(log.logits[first + k - 1])[slot, 0]
+            np.testing.assert_allclose(
+                got, full[p - 1 + k], rtol=2e-3, atol=2e-3,
+                err_msg=f"{arch}: request {r.rid}, token {k}")
+
+
 def test_timing_helpers_run():
     eng = _engine("granite-3-2b", max_len=48)
     batch = make_batch(eng.cfg, 2, 8, 0)
